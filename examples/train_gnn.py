@@ -18,6 +18,10 @@ inside the ring (fused with the tile transfers).  ``--tune-cache``
 persists the converged config(s) keyed by workload shape + hardware, so
 the next run warm-starts from it.
 
+``--metrics-json PATH`` writes the metrics registry: step times, the
+tuner's counters, and JAX's compile events (``jax.compile.*``: compiles and
+their seconds by phase, persistent-cache hits and misses).
+
 ``--sample-fanout F`` switches to the sampled mini-batch path
 (GraphSAGE only): fanout-bounded k-hop blocks (repro.sample) over a
 tiered feature store — the per-step working set is bounded by
@@ -41,7 +45,7 @@ import jax.numpy as jnp
 
 import repro.core as C
 from repro.dist import flat_ring_mesh
-from repro.obs import MetricsRegistry, Tracer
+from repro.obs import MetricsRegistry, Tracer, watch_compiles
 from repro.runtime import DynamicGNNEngine, ProfileConfig
 from repro.train.data import graph_features
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
@@ -205,7 +209,14 @@ def main():
 
     tracer = Tracer() if args.trace else None
     registry = MetricsRegistry()
+    # JAX's compile events (the re-jits after a tuner move included) land
+    # in the registry as jax.compile.*, and so in --metrics-json
+    with watch_compiles(registry):
+        train(args, ap, tracer, registry)
 
+
+def train(args, ap, tracer, registry):
+    """The training run that main's arguments ask for."""
     g, meta = C.paper_dataset(args.dataset, scale=0.5)
     # demo-friendly label space (the full #Class makes a 100-step CPU demo
     # unconvincing; benchmarks/table5 runs the accuracy study properly)
@@ -242,7 +253,7 @@ def main():
         )
     else:
         eng = C.GNNEngine.build(g, mesh, ps=16, dist=2,
-                                fuse_update=args.fuse_update)
+                                fuse_update=args.fuse_update, tracer=tracer)
     opt = adamw_init(params)
     ocfg = AdamWConfig(lr=5e-3, warmup_steps=5, total_steps=args.steps,
                        weight_decay=0.0)

@@ -22,6 +22,10 @@ shared graph partition and PGAS layout.
 
 Everything operates in the padded PGAS layout (placement.pad_embeddings);
 ``deg`` vectors are padded alongside.
+
+The compiled step names its layers (``repro.obs.scopes``): the engine's
+aggregations run under ``mgg.aggregate``, each stage's dense matmul, bias
+and activation under ``gnn.update``, the loss under ``gnn.loss``.
 """
 from __future__ import annotations
 
@@ -33,6 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.obs.scopes import AGGREGATE, LOSS, UPDATE, scoped
+from repro.obs.tracer import resolve
 
 from .graph import CSRGraph
 from .placement import (AggregationPlan, LayerPlan, SharedPartition,
@@ -91,6 +98,7 @@ class GNNEngine:
         topk: Optional[int] = None,
         layer_configs: Optional[Sequence[Dict]] = None,
         partition: Optional[SharedPartition] = None,
+        tracer=None,
     ) -> "GNNEngine":
         """Build an engine; ``layer_configs`` (one ``{ps, dist, pb, ...}``
         dict per layer) selects per-layer plans, otherwise the single
@@ -98,26 +106,36 @@ class GNNEngine:
         reuses a previously built :class:`SharedPartition` (it must match
         this graph *after* self-loop handling and this mesh's device
         count) — the dynamic runtime passes it so tuner moves re-derive
-        schedules without re-partitioning the graph."""
-        g = graph.with_self_loops() if self_loops else graph
-        n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names])) \
-            if axis_name == "__all__" else mesh.shape[axis_name]
-        if layer_configs is None:
-            layer_configs = [dict(ps=ps, dist=dist, pb=pb)]
-        part = partition if partition is not None \
-            else build_partition(g, n_dev)
-        plans = build_layer_plans(g, n_dev, layer_configs, partition=part,
-                                  interleave=interleave,
-                                  fuse_update=fuse_update, topk=topk)
-        plan0 = plans[0].plan
-        deg = pad_table(plan0.bounds, plan0.rows_per_dev,
-                        g.degrees.astype(np.float32)[:, None])[:, 0]
-        return GNNEngine(
-            layer_plans=plans, mesh=mesh, axis_name=axis_name,
-            use_kernel=use_kernel,
-            deg=jnp.asarray(np.maximum(deg, 1.0)),
-            partition=part,
-        )
+        schedules without re-partitioning the graph.
+
+        ``tracer`` (optional :class:`repro.obs.Tracer`) records an
+        ``engine.build`` span with the children ``engine.partition`` and
+        ``engine.layer_plans``."""
+        tracer = resolve(tracer)
+        with tracer.span("engine.build", cat="engine"):
+            g = graph.with_self_loops() if self_loops else graph
+            n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names])) \
+                if axis_name == "__all__" else mesh.shape[axis_name]
+            if layer_configs is None:
+                layer_configs = [dict(ps=ps, dist=dist, pb=pb)]
+            with tracer.span("engine.partition", cat="engine"):
+                part = partition if partition is not None \
+                    else build_partition(g, n_dev)
+            with tracer.span("engine.layer_plans", cat="engine"):
+                plans = build_layer_plans(g, n_dev, layer_configs,
+                                          partition=part,
+                                          interleave=interleave,
+                                          fuse_update=fuse_update,
+                                          topk=topk)
+            plan0 = plans[0].plan
+            deg = pad_table(plan0.bounds, plan0.rows_per_dev,
+                            g.degrees.astype(np.float32)[:, None])[:, 0]
+            return GNNEngine(
+                layer_plans=plans, mesh=mesh, axis_name=axis_name,
+                use_kernel=use_kernel,
+                deg=jnp.asarray(np.maximum(deg, 1.0)),
+                partition=part,
+            )
 
     # -- layer plan access ---------------------------------------------------
 
@@ -246,12 +264,14 @@ class GNNEngine:
             tracer=tracer,
         )
 
+    @scoped(AGGREGATE)
     def gcn_norm_aggregate(self, x: jax.Array, layer: int = 0,
                            topk: Optional[int] = None) -> jax.Array:
         """Â x with Â = D^{-1/2}(A+I)D^{-1/2} (self-loops already in plan)."""
         dinv = jax.lax.rsqrt(self.deg)[:, None].astype(x.dtype)
         return self.aggregate(x * dinv, layer=layer, topk=topk) * dinv
 
+    @scoped(AGGREGATE)
     def gcn_norm_aggregate_update(self, x: jax.Array, w: jax.Array,
                                   layer: int = 0,
                                   topk: Optional[int] = None) -> jax.Array:
@@ -261,11 +281,13 @@ class GNNEngine:
         return self.aggregate_update(x * dinv, w, layer=layer, topk=topk) \
             * dinv
 
+    @scoped(AGGREGATE)
     def mean_aggregate(self, x: jax.Array, layer: int = 0,
                        topk: Optional[int] = None) -> jax.Array:
         return self.aggregate(x, layer=layer, topk=topk) \
             / self.deg[:, None].astype(x.dtype)
 
+    @scoped(AGGREGATE)
     def mean_aggregate_update(self, x: jax.Array, w: jax.Array,
                               layer: int = 0,
                               topk: Optional[int] = None) -> jax.Array:
@@ -315,17 +337,24 @@ def gcn_stage(params: Dict, engine: GNNEngine, h: jax.Array,
     d_in, d_out = layer["w"].shape
     tk = engine.stage_topk(i)  # hidden layers may ride the sparse ring
     if engine.layer_plan(i).fuse_update:
-        h = engine.gcn_norm_aggregate_update(h, layer["w"], layer=i,
-                                             topk=tk) + layer["b"]
+        h = engine.gcn_norm_aggregate_update(h, layer["w"], layer=i, topk=tk)
+        with jax.named_scope(UPDATE):
+            h = h + layer["b"]
     elif d_in >= d_out:
         # transform-first; bias after aggregation (PyG convention) so all
         # three dataflows compute identical math up to summation order
-        h = engine.gcn_norm_aggregate(h @ layer["w"], layer=i, topk=tk) \
-            + layer["b"]
+        with jax.named_scope(UPDATE):
+            h = h @ layer["w"]
+        h = engine.gcn_norm_aggregate(h, layer=i, topk=tk)
+        with jax.named_scope(UPDATE):
+            h = h + layer["b"]
     else:
-        h = _dense(layer, engine.gcn_norm_aggregate(h, layer=i, topk=tk))
+        h = engine.gcn_norm_aggregate(h, layer=i, topk=tk)
+        with jax.named_scope(UPDATE):
+            h = _dense(layer, h)
     if i < n - 1:
-        h = jax.nn.relu(h)
+        with jax.named_scope(UPDATE):
+            h = jax.nn.relu(h)
     return h
 
 
@@ -362,18 +391,23 @@ def gin_stage(params: Dict, engine: GNNEngine, h: jax.Array,
     plain local matmul.
     """
     if i == len(params["layers"]):
-        return _dense(params["head"], h)
+        with jax.named_scope(UPDATE):
+            return _dense(params["head"], h)
     layer = params["layers"][i]
     tk = engine.stage_topk(i)  # sparse ring for hidden layers; self term dense
     if engine.layer_plan(i).fuse_update:
-        z = engine.aggregate_update(h, layer["mlp1"]["w"], layer=i, topk=tk) \
-            + layer["eps"] * (h @ layer["mlp1"]["w"]) + layer["mlp1"]["b"]
-        z = jax.nn.relu(z)
+        agg = engine.aggregate_update(h, layer["mlp1"]["w"], layer=i, topk=tk)
+        with jax.named_scope(UPDATE):
+            z = agg + layer["eps"] * (h @ layer["mlp1"]["w"]) \
+                + layer["mlp1"]["b"]
+            z = jax.nn.relu(z)
     else:
         agg = engine.aggregate(h, layer=i, topk=tk)  # Σ nbrs (+ self-loop)
-        z = agg + layer["eps"] * h  # (1+ε)h + Σ_{u∈N(v)}: self-loop gives 1·h
-        z = jax.nn.relu(_dense(layer["mlp1"], z))
-    return jax.nn.relu(_dense(layer["mlp2"], z))
+        with jax.named_scope(UPDATE):
+            z = agg + layer["eps"] * h  # (1+ε)h + Σ_{u∈N(v)}: self-loop → 1·h
+            z = jax.nn.relu(_dense(layer["mlp1"], z))
+    with jax.named_scope(UPDATE):
+        return jax.nn.relu(_dense(layer["mlp2"], z))
 
 
 def gin_apply(params: Dict, engine: GNNEngine, x: jax.Array) -> jax.Array:
@@ -399,13 +433,18 @@ def sage_stage(params: Dict, engine: GNNEngine, h: jax.Array,
     layer = params["layers"][i]
     tk = engine.stage_topk(i)  # sparse ring for hidden layers; self path dense
     if engine.layer_plan(i).fuse_update:
-        nbr = engine.mean_aggregate_update(h, layer["nbr"]["w"], layer=i,
-                                           topk=tk) + layer["nbr"]["b"]
+        agg = engine.mean_aggregate_update(h, layer["nbr"]["w"], layer=i,
+                                           topk=tk)
+        with jax.named_scope(UPDATE):
+            nbr = agg + layer["nbr"]["b"]
     else:
-        nbr = _dense(layer["nbr"], engine.mean_aggregate(h, layer=i, topk=tk))
-    h = _dense(layer["self"], h) + nbr
-    if i < len(params["layers"]) - 1:
-        h = jax.nn.relu(h)
+        agg = engine.mean_aggregate(h, layer=i, topk=tk)
+        with jax.named_scope(UPDATE):
+            nbr = _dense(layer["nbr"], agg)
+    with jax.named_scope(UPDATE):
+        h = _dense(layer["self"], h) + nbr
+        if i < len(params["layers"]) - 1:
+            h = jax.nn.relu(h)
     return h
 
 
@@ -438,11 +477,14 @@ def sage_apply_blocks(params: Dict, h: jax.Array, blocks,
     for i, (layer, blk) in enumerate(zip(layers, blocks)):
         nbr, mask = blk["nbr"], blk["mask"]
         s = block_neighbor_sum(h, nbr, mask, use_kernel=use_kernel)
-        deg = jnp.maximum(mask.sum(axis=-1), 1.0).astype(h.dtype)[:, None]
-        h = _dense(layer["self"], h[:nbr.shape[0]]) + _dense(
-            layer["nbr"], s / deg)
-        if i < len(layers) - 1:
-            h = jax.nn.relu(h)
+        with jax.named_scope(AGGREGATE):
+            deg = jnp.maximum(mask.sum(axis=-1), 1.0).astype(h.dtype)[:, None]
+            s = s / deg
+        with jax.named_scope(UPDATE):
+            h = _dense(layer["self"], h[:nbr.shape[0]]) + _dense(
+                layer["nbr"], s)
+            if i < len(layers) - 1:
+                h = jax.nn.relu(h)
     return h
 
 
@@ -462,6 +504,7 @@ def apply_blocks(model: str, params: Dict, h: jax.Array, blocks,
     return BLOCK_MODELS[model](params, h, blocks, use_kernel=use_kernel)
 
 
+@scoped(LOSS)
 def masked_cross_entropy(logits: jax.Array, labels: jax.Array,
                          mask: jax.Array) -> jax.Array:
     """Mean CE over real (non-padding) nodes; padded rows carry mask 0."""
@@ -500,19 +543,25 @@ def gat_stage(params: Dict, engine: GNNEngine, h: jax.Array,
     # fuse_update flag is a no-op and fused == unfused bitwise.  topk is
     # likewise not honoured: zeroing entries of the e^s attention numerator
     # and denominator aggregations would bias the softmax, not sparsify it.
+    # The dense transform and the attention scores are the update; the
+    # weighted messages, their sums and the softmax division aggregate.
     layer = params["layers"][i]
     nh = layer["a_l"].shape[0]                 # heads (static)
-    z = _dense(layer["w"], h)                  # (N, H·hd)
-    npad, total = z.shape
-    hd = total // nh
-    zh = z.reshape(npad, nh, hd)
-    s = jnp.einsum("nhd,hd->nh", zh, layer["a_l"])
-    e = jnp.exp(jax.nn.leaky_relu(s, 0.2))     # source weights (N, H)
-    num = engine.aggregate((zh * e[..., None]).reshape(npad, total), layer=i)
-    den = engine.aggregate(jnp.repeat(e, hd, axis=1), layer=i)
-    out = (num / jnp.maximum(den, 1e-9)).astype(h.dtype)
+    with jax.named_scope(UPDATE):
+        z = _dense(layer["w"], h)              # (N, H·hd)
+        npad, total = z.shape
+        hd = total // nh
+        zh = z.reshape(npad, nh, hd)
+        s = jnp.einsum("nhd,hd->nh", zh, layer["a_l"])
+        e = jnp.exp(jax.nn.leaky_relu(s, 0.2))  # source weights (N, H)
+    with jax.named_scope(AGGREGATE):
+        num = engine.aggregate((zh * e[..., None]).reshape(npad, total),
+                               layer=i)
+        den = engine.aggregate(jnp.repeat(e, hd, axis=1), layer=i)
+        out = (num / jnp.maximum(den, 1e-9)).astype(h.dtype)
     if i < len(params["layers"]) - 1:
-        out = jax.nn.elu(out)
+        with jax.named_scope(UPDATE):
+            out = jax.nn.elu(out)
     return out
 
 

@@ -32,6 +32,11 @@ Three baselines used throughout benchmarks:
   second, with a ``page_rows`` granularity knob.  ``page_rows=1`` models the
   Direct-NVSHMEM baseline (exact rows, no overlap; Table 1); larger values
   model UVM's page-granular migration with its wasted bandwidth (§2.2).
+
+Inside a jitted program every aggregation runs under the ``mgg.aggregate``
+scope and each ring rotation under ``mgg.ring`` (``repro.obs.scopes``).
+The streamed path's pieces are jitted on their own, so their bodies carry
+the scope themselves.
 """
 from __future__ import annotations
 
@@ -43,6 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.obs.scopes import AGGREGATE, RING, scoped
 
 from .placement import AggregationPlan
 
@@ -89,6 +96,7 @@ def _gather_sum(buf: jax.Array, nbrs: jax.Array, mask: jax.Array,
     )
 
 
+@scoped(AGGREGATE)
 def block_neighbor_sum(h_src: jax.Array, nbr: jax.Array, mask: jax.Array, *,
                        use_kernel: bool = False,
                        acc_dtype=jnp.float32) -> jax.Array:
@@ -191,6 +199,13 @@ def _plan_specs(axis_name: str) -> Dict[str, P]:
 # MGG pipelined ring aggregation
 # ---------------------------------------------------------------------------
 
+def _rotate(x: jax.Array, axis_name: str, perm) -> jax.Array:
+    """One ring rotation (``lax.ppermute``), under the ring's scope."""
+    with jax.named_scope(RING):
+        return lax.ppermute(x, axis_name, perm)
+
+
+@scoped(AGGREGATE)
 def mgg_aggregate(
     x: jax.Array,
     plan: AggregationPlan,
@@ -320,11 +335,11 @@ def _mgg_shard_body(
     # One double-buffered ring per tile chunk (chunk-major, so every chunk
     # performs exactly n_dev - 1 permutes — no wasted trailing rotation).
     for c in range(dist):
-        cur = lax.ppermute(tiles[c], axis_name, perm)  # rotation 1 (prologue)
+        cur = _rotate(tiles[c], axis_name, perm)  # rotation 1 (prologue)
 
         def body(k, carry, c=c):
             cur, out = carry
-            nxt = lax.ppermute(cur, axis_name, perm)  # rotation k+2 — no dep
+            nxt = _rotate(cur, axis_name, perm)       # rotation k+2 — no dep
             out = step_work(out, cur, k * dist + c)   # on the aggregation ⇒
             return (nxt, out)                          # XLA overlaps DMA+compute
 
@@ -338,6 +353,7 @@ def _mgg_shard_body(
 # MGG sparse ring aggregation: top-k compressed payload on the wire
 # ---------------------------------------------------------------------------
 
+@scoped(AGGREGATE)
 def mgg_aggregate_sparse(
     x: jax.Array,
     plan: AggregationPlan,
@@ -460,13 +476,13 @@ def _mgg_sparse_shard_body(
     # Same chunk-major double-buffered rings as the dense body — only the
     # payload narrows: both halves of the compressed pair ride each rotation.
     for c in range(dist):
-        cur_v = lax.ppermute(v_tiles[c], axis_name, perm)
-        cur_i = lax.ppermute(i_tiles[c], axis_name, perm)
+        cur_v = _rotate(v_tiles[c], axis_name, perm)
+        cur_i = _rotate(i_tiles[c], axis_name, perm)
 
         def body(s, carry, c=c):
             cur_v, cur_i, out = carry
-            nxt_v = lax.ppermute(cur_v, axis_name, perm)  # rotation s+2
-            nxt_i = lax.ppermute(cur_i, axis_name, perm)  # — no dep on the
+            nxt_v = _rotate(cur_v, axis_name, perm)  # rotation s+2
+            nxt_i = _rotate(cur_i, axis_name, perm)  # — no dep on the
             out = step_work(out, cur_v, cur_i, s * dist + c)  # aggregation
             return (nxt_v, nxt_i, out)
 
@@ -652,6 +668,7 @@ def _streamed_local_fn(mesh, axis_name, use_kernel, acc_dtype, pb, fused):
 @functools.lru_cache(maxsize=None)
 def _streamed_assemble_fn(mesh, axis_name, n_dev, dist):
     """Chunk arrays → the full shard (chunk-minor → row-major per device)."""
+    @scoped(AGGREGATE)
     def assemble(*chs):
         tile_rows = chs[0].shape[0] // n_dev
         st = jnp.stack(chs, axis=0)            # (dist, n_dev·tile, D)
@@ -680,6 +697,7 @@ def _streamed_init(w, d_feat, acc_dtype, fused):
     return (lambda partial: partial), d_feat
 
 
+@scoped(AGGREGATE)
 def _streamed_chunk_body(tile, arrays, w=None, *, axis_name, n_dev, dist,
                          chunk, use_kernel, acc_dtype, pb=None, fused=False):
     """One chunk's remote ring: only the steps ``s`` with
@@ -693,11 +711,11 @@ def _streamed_chunk_body(tile, arrays, w=None, *, axis_name, n_dev, dist,
     out = lax.pcast(out, (axis_name,), to="varying")
 
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-    cur = lax.ppermute(tile, axis_name, perm)  # rotation 1 (prologue)
+    cur = _rotate(tile, axis_name, perm)  # rotation 1 (prologue)
 
     def body(k, carry):
         cur, out = carry
-        nxt = lax.ppermute(cur, axis_name, perm)   # rotation k+2 — no dep
+        nxt = _rotate(cur, axis_name, perm)   # rotation k+2 — no dep
         out = _streamed_step(out, cur, k * dist + chunk, r_nbrs, r_mask,
                              r_tgt, update, use_kernel, acc_dtype, pb)
         return (nxt, out)
@@ -708,6 +726,7 @@ def _streamed_chunk_body(tile, arrays, w=None, *, axis_name, n_dev, dist,
     return out
 
 
+@scoped(AGGREGATE)
 def _streamed_local_body(x, arrays, w=None, *, axis_name, use_kernel,
                          acc_dtype, pb=None, fused=False):
     """The local pass over the fully assembled shard (runs last)."""
@@ -845,6 +864,7 @@ def mgg_aggregate_sparse_streamed(
 @functools.lru_cache(maxsize=None)
 def _sparse_compress_fn(mesh, axis_name, k, wire_dtype_name):
     """jitted per-chunk row-wise top-k compression, sharding-preserving."""
+    @scoped(AGGREGATE)
     def compress(chunk):
         values, idx = lax.top_k(chunk, k)
         return values, idx.astype(jnp.dtype(wire_dtype_name))
@@ -882,6 +902,7 @@ def _sparse_streamed_local_fn(mesh, axis_name, d_feat, use_kernel, acc_dtype,
                                  out_specs=P(axis_name), check_vma=False))
 
 
+@scoped(AGGREGATE)
 def _sparse_streamed_chunk_body(v_tile, i_tile, arrays, w=None, *, axis_name,
                                 n_dev, dist, chunk, d_feat, use_kernel,
                                 acc_dtype, pb=None, fused=False):
@@ -902,13 +923,13 @@ def _sparse_streamed_chunk_body(v_tile, i_tile, arrays, w=None, *, axis_name,
             cur_v, cur_i, nbrs, mask, d_feat, use_kernel, acc_dtype, pb)))
 
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-    cur_v = lax.ppermute(v_tile, axis_name, perm)  # rotation 1 (prologue)
-    cur_i = lax.ppermute(i_tile, axis_name, perm)
+    cur_v = _rotate(v_tile, axis_name, perm)  # rotation 1 (prologue)
+    cur_i = _rotate(i_tile, axis_name, perm)
 
     def body(s, carry):
         cur_v, cur_i, out = carry
-        nxt_v = lax.ppermute(cur_v, axis_name, perm)   # rotation s+2 — no dep
-        nxt_i = lax.ppermute(cur_i, axis_name, perm)
+        nxt_v = _rotate(cur_v, axis_name, perm)   # rotation s+2 — no dep
+        nxt_i = _rotate(cur_i, axis_name, perm)
         out = step(out, cur_v, cur_i, s * dist + chunk)
         return (nxt_v, nxt_i, out)
 
@@ -918,6 +939,7 @@ def _sparse_streamed_chunk_body(v_tile, i_tile, arrays, w=None, *, axis_name,
     return out
 
 
+@scoped(AGGREGATE)
 def _sparse_streamed_local_body(values, idx, arrays, w=None, *, axis_name,
                                 d_feat, use_kernel, acc_dtype, pb=None,
                                 fused=False):
@@ -936,6 +958,7 @@ def _sparse_streamed_local_body(values, idx, arrays, w=None, *, axis_name,
 # Baseline 1: bulk all-gather + local aggregation (DGCL / NCCL pattern)
 # ---------------------------------------------------------------------------
 
+@scoped(AGGREGATE)
 def bulk_aggregate(
     x: jax.Array,
     bulk_nbrs: np.ndarray,   # (n_dev, P, ps) offsets into the padded table
@@ -973,6 +996,7 @@ def bulk_aggregate(
 # Baseline 2: fetch-then-aggregate with a granularity knob (UVM / Direct)
 # ---------------------------------------------------------------------------
 
+@scoped(AGGREGATE)
 def fetch_rows_aggregate(
     x: jax.Array,
     fetch_rows: np.ndarray,   # (n_dev, F) padded-global row ids to fetch

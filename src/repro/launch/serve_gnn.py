@@ -211,7 +211,7 @@ def main() -> None:
                 tracer=rtr, metrics=registry)
         else:
             eng = C.GNNEngine.build(g, mesh, ps=8, dist=1,
-                                    fuse_update=args.fuse_update)
+                                    fuse_update=args.fuse_update, tracer=rtr)
         labels = {"replica": idx} if args.replicas > 1 else {}
         return GNNServeEngine(eng, params, args.model, x, g,
                               slots=args.slots,

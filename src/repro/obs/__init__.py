@@ -7,10 +7,18 @@ Two primitives:
 
 - :class:`Tracer` (tracer.py): nestable wall-clock spans with an
   injectable monotonic clock, bounded ring buffer, thread safety, and a
-  strict no-op fast path when disabled.  Exports Chrome-trace JSON
-  (opens directly in ui.perfetto.dev) and JSONL.
+  strict no-op fast path when disabled.  A live span also annotates the
+  JAX profiler's trace.  Exports Chrome-trace JSON (opens directly in
+  ui.perfetto.dev) and JSONL.
 - :class:`MetricsRegistry` (metrics.py): labeled counters / gauges /
   histograms with percentile summaries and a JSON snapshot.
+
+and two that name what happens inside JAX:
+
+- scopes.py: the layer names the compiled GNN step carries
+  (``jax.named_scope``), kept once.
+- :func:`watch_compiles` (compiles.py): JAX's compile and compile-cache
+  events, fed into a registry.
 
 One exception to leaf-ness, deliberately quarantined: ``calibrate.py``
 fits the §4 analytical latency model to measured latencies and so must
@@ -23,9 +31,10 @@ See docs/observability.md for the span taxonomy and metric names.
 """
 from repro.obs.tracer import NULL_TRACER, Tracer, merge_traces
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.compiles import watch_compiles
 
 __all__ = ["Tracer", "NULL_TRACER", "MetricsRegistry", "merge_traces",
-           "calibrate"]
+           "watch_compiles", "calibrate"]
 
 
 def __getattr__(name):

@@ -6,8 +6,14 @@ Design constraints (the acceptance criteria of the observability PR):
   every hot loop in the repo takes a ``tracer`` argument and must pay
   (almost) nothing when observability wasn't requested.  A disabled
   tracer's ``span()`` returns one preallocated context manager whose
-  ``__enter__``/``__exit__`` do nothing; ``instant``/``complete``/
-  ``counter`` return immediately on a single attribute check.
+  ``__enter__``/``__exit__`` do nothing; ``instant``/``complete``
+  return immediately on a single attribute check.
+- **On the profiler's clock.** A live span of an enabled tracer also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so while a
+  profiler trace runs the span sits on its host plane, on the device
+  trace's clock.  Retroactive ``complete()`` spans and ``instant()``
+  markers stay in this tracer only: their times were read on its own
+  clock, after the fact.
 - **Injectable clock.** Everything times through ``self._clock`` (default
   ``time.perf_counter``) so tests drive spans deterministically — the
   same pattern as ``runtime.profiler``.
@@ -31,6 +37,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class _NullSpan:
     """Shared do-nothing context manager handed out by disabled tracers."""
@@ -51,9 +59,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span: records begin time at __enter__, emits at __exit__."""
+    """A live span: records begin time at __enter__, emits at __exit__,
+    and holds a profiler annotation of its name open in between."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict]):
@@ -62,14 +71,17 @@ class _Span:
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, *exc):
         self._tracer.complete(self.name, self._t0, self._tracer._clock(),
                               cat=self.cat, args=self.args)
+        self._annotation.__exit__(*exc)
         return False
 
     def set(self, **args) -> None:
@@ -134,15 +146,6 @@ class Tracer:
         if args:
             ev["args"] = args
         self._push(ev)
-
-    def counter(self, name: str, *, tid: int = 0, **values) -> None:
-        """Record a counter sample (Perfetto renders a stacked track)."""
-        if not self.enabled:
-            return
-        self._push({"ph": "C", "name": name, "cat": "counter",
-                    "ts": (self._clock() - self._epoch) * 1e6,
-                    "pid": self._pid, "tid": int(tid),
-                    "args": {k: float(v) for k, v in values.items()}})
 
     def now(self) -> float:
         """Clock reading, for callers building retroactive spans."""

@@ -12,6 +12,8 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import OPTIMIZER, scoped
+
 __all__ = ["AdamWConfig", "adamw_init", "adamw_update", "global_norm",
            "cosine_schedule", "linear_warmup"]
 
@@ -64,6 +66,7 @@ def linear_warmup(cfg: AdamWConfig) -> Callable[[jax.Array], jax.Array]:
     return lr
 
 
+@scoped(OPTIMIZER)
 def adamw_update(
     grads: Any, state: Any, params: Any, cfg: AdamWConfig,
     schedule: Optional[Callable] = None,

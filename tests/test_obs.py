@@ -2,21 +2,31 @@
 validator, and the PR's two proofs of innocence — (1) tracing ON
 produces bitwise-identical streamed aggregations, served logits and
 training losses vs tracing OFF, and (2) the disabled-tracer path adds
-bounded (<2%) overhead to an instrumented hot loop."""
+bounded (<2%) overhead to an instrumented hot loop.  Also what names the
+program inside JAX: the layer scopes of the compiled step (metadata
+only), tracer spans on the profiler's trace, and the compile watcher."""
+import contextlib
+import glob
 import json
+import re
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 import repro.core as C
 from repro.core.pipeline import mgg_aggregate_streamed
 from repro.dist import flat_ring_mesh
-from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
+from repro.obs import NULL_TRACER, MetricsRegistry, Tracer, watch_compiles
+from repro.obs import scopes as S
 from repro.obs.validate import validate
 from repro.serve import GNNServeEngine, TrafficPhase, ZipfTraffic, run_trace
 from repro.store import FeatureStore, TieredFeatures
 from repro.train import Trainer, TrainState
+from repro.train import optimizer as O
 
 
 class FakeClock:
@@ -67,19 +77,20 @@ def test_nested_spans_and_epoch_relative_timestamps():
 
 
 def test_complete_instant_counter_event_shapes():
+    """Complete and instant events; counts live in a MetricsRegistry, so
+    the tracer records no counter events."""
     clk = FakeClock()
     tr = Tracer(clock=clk)
     t0 = tr.now()
     clk.tick(3.0)
     tr.complete("retro", t0, tr.now(), cat="c", tid=2, args={"a": 1})
     tr.instant("mark", cat="ev", hit=True)
-    tr.counter("depth", queued=4)
-    retro, mark, depth = tr.events()
+    retro, mark = tr.events()
     assert retro["ph"] == "X" and retro["dur"] == pytest.approx(3e6) \
         and retro["tid"] == 2
     assert mark["ph"] == "i" and mark["s"] == "t" \
         and mark["args"] == {"hit": True}
-    assert depth["ph"] == "C" and depth["args"] == {"queued": 4.0}
+    assert not hasattr(tr, "counter")
 
 
 def test_disabled_tracer_is_a_strict_noop():
@@ -90,7 +101,6 @@ def test_disabled_tracer_is_a_strict_noop():
     with s1 as sp:
         sp.set(anything=1)
     tr.instant("i")
-    tr.counter("c", v=1)
     tr.complete("x", 0.0, 1.0)
     assert len(tr) == 0 and tr.events() == []
     assert len(NULL_TRACER) == 0
@@ -122,6 +132,164 @@ def test_dump_chrome_and_jsonl_roundtrip(tmp_path):
     assert [e["name"] for e in doc["traceEvents"]] == ["s", "i"]
     lines = [json.loads(l) for l in open(jsonl)]
     assert lines == doc["traceEvents"]
+
+
+def test_span_lands_on_profiler_trace_around_its_op(tmp_path):
+    """An enabled tracer's live span is also a profiler annotation: on a
+    CPU profile it brackets the jitted op it wraps, on the same clock."""
+    def square_tanh(x):
+        return jnp.tanh(x @ x)
+
+    f = jax.jit(square_tanh)
+    x = jnp.ones((128, 128))
+    f(x).block_until_ready()                      # compiled before the trace
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs.test_span"):
+            f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [ev for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events]
+    spans = [(ev.start_ns, ev.end_ns) for ev in events
+             if ev.name == "obs.test_span"]
+    ops = [(ev.start_ns, ev.end_ns) for ev in events
+           if ("hlo_module", "jit_square_tanh") in list(ev.stats)]
+    assert len(spans) == 1 and ops
+    (s0, s1), = spans
+    assert all(s0 <= a and b <= s1 for a, b in ops)
+    assert [e["name"] for e in tr.events()] == ["obs.test_span"]
+
+
+def test_engine_build_records_its_phases():
+    g = C.power_law(60, avg_degree=5.0, locality=0.3, seed=3)
+    tr = Tracer()
+    C.GNNEngine.build(g, flat_ring_mesh(1), ps=4, dist=2, tracer=tr)
+    evs = {e["name"]: e for e in tr.events()}
+    assert set(evs) == {"engine.build", "engine.partition",
+                        "engine.layer_plans"}
+    b = evs["engine.build"]
+    for child in ("engine.partition", "engine.layer_plans"):
+        c = evs[child]
+        assert b["ts"] <= c["ts"] and c["ts"] + c["dur"] <= b["ts"] + b["dur"]
+
+
+# ---------------------------------------------------------------------------
+# layer scopes of the compiled step
+# ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def _cache_settings(**settings):
+    """JAX's compile-cache settings for one test, restored after it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = {k: getattr(jax.config, k) for k in settings}
+    for k, v in settings.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """The persistent compile cache in a fresh directory, keeping every
+    compile."""
+    with _cache_settings(
+            jax_enable_compilation_cache=True,
+            jax_compilation_cache_dir=str(tmp_path / "cache"),
+            jax_persistent_cache_min_compile_time_secs=0.0,
+            jax_persistent_cache_min_entry_size_bytes=0):
+        yield
+
+
+@pytest.fixture
+def no_compile_cache():
+    with _cache_settings(jax_enable_compilation_cache=False):
+        yield
+
+
+def _tiny_gcn_step():
+    """A GCN training step (the benchmark's, at a CPU size): the step
+    function and its arguments."""
+    g = C.power_law(96, avg_degree=6.0, locality=0.3, seed=4)
+    eng = C.GNNEngine.build(g, flat_ring_mesh(1), ps=8, dist=2)
+    init, apply, kw = C.MODEL_ZOO["gcn"]
+    params = init(jax.random.key(0), 12, 4, **kw)
+    rng = np.random.default_rng(0)
+    rows = eng.plan.rows_per_dev
+    xp = eng.shard(eng.pad(rng.normal(size=(96, 12)).astype(np.float32)))
+    yp = eng.shard(rng.integers(0, 4, rows).astype(np.int32))
+    mp = eng.shard((rng.random(rows) < 0.6).astype(np.float32))
+    cfg = O.AdamWConfig(lr=5e-3, warmup_steps=2)
+
+    def step(params, opt, xp, yp, mp):
+        loss, grads = jax.value_and_grad(lambda p: C.masked_cross_entropy(
+            apply(p, eng, xp), yp, mp))(params)
+        params, opt, _ = O.adamw_update(grads, opt, params, cfg)
+        return params, opt, loss
+    return step, (params, O.adamw_init(params), xp, yp, mp)
+
+
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+
+
+def _compile_and_run(step, args):
+    compiled = jax.jit(step).lower(*args).compile()
+    params, opt, xp, yp, mp = args
+    losses = []
+    for _ in range(3):
+        params, opt, loss = compiled(params, opt, xp, yp, mp)
+        losses.append(np.asarray(loss))
+    return compiled.as_text(), losses
+
+
+def test_scopes_change_metadata_only(no_compile_cache, monkeypatch):
+    """The step compiled with the layer scopes and with ``named_scope``
+    made a no-op differs in its op names alone: the same HLO once the
+    metadata is stripped, and bitwise the same losses."""
+    step, args = _tiny_gcn_step()
+    scoped, scoped_losses = _compile_and_run(step, args)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()                 # trace the step again, unnamed
+    plain, plain_losses = _compile_and_run(step, args)
+    for scope in (S.AGGREGATE, S.UPDATE, S.LOSS, S.OPTIMIZER):
+        assert scope in scoped and scope not in plain
+    strip = lambda t: _METADATA.sub("", t.split("\nFileNames")[0])
+    assert strip(scoped) == strip(plain)
+    for a, b in zip(scoped_losses, plain_losses):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_compile_watcher_counts_compile_and_cache_lookup(compile_cache):
+    def sin_affine(x):
+        return jnp.sin(x) * 3.0 + 1.0
+
+    x = jnp.arange(8.0)
+    first, second = MetricsRegistry(), MetricsRegistry()
+    with watch_compiles(first):
+        jax.jit(sin_affine).lower(x).compile()
+    jax.clear_caches()
+    with watch_compiles(second):
+        jax.jit(sin_affine).lower(x).compile()
+    for reg in (first, second):
+        assert reg.histogram("jax.compile.backend_s").count == 1
+        assert reg.histogram("jax.compile.lower_s").count == 1
+    assert reg.histogram("jax.compile.trace_s").count >= 1
+    assert first.counter("jax.compile.cache_misses").value == 1
+    assert first.counter("jax.compile.cache_hits").value == 0
+    assert second.counter("jax.compile.cache_hits").value == 1
+    assert second.counter("jax.compile.cache_misses").value == 0
+    # the listeners are gone after the block
+    jax.clear_caches()
+    jax.jit(sin_affine).lower(x).compile()
+    assert second.counter("jax.compile.cache_hits").value == 1
 
 
 # ---------------------------------------------------------------------------

@@ -9,17 +9,28 @@ shapes off Mosaic's (8, 128) tiling, and scalar-prefetched tables larger
 than SMEM.  Nothing runs, so nothing here says anything about results or
 speed.
 
+The same fixture compiles a tiny GCN training step for the described chip,
+to check that the TPU compiler's fusions keep the program's layer scopes
+(``repro.obs.scopes``) on the ops that carry each layer's time.
+
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
+import repro.core as C
+from chipbench.scopes import hlo_scopes, matmul_ops, scope_of
 from repro.kernels import neighbor_agg, ops
+from repro.obs import scopes as S
+from repro.train import optimizer as O
 
 D = 602               # reddit's feature width
 T = 4096              # rows of the gathered table
@@ -103,3 +114,113 @@ def test_unchunked_table_exceeds_smem(one_chip, monkeypatch):
         jax.jit(unchunked).lower(
             _spec(one_chip, (T, 640)), _spec(one_chip, (P, PS), jnp.int32),
             _spec(one_chip, (P, PS), jnp.int32)).compile()
+
+
+# ---------------------------------------------------------------------------
+# the layer scopes of a GCN step, through the TPU compiler's fusions
+# ---------------------------------------------------------------------------
+
+_ENTRY_OP = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (\(.*?\)|\S+) "
+                       r"([a-z][a-z0-9\-]*)\(")
+D_IN, CLASSES = 24, 5   # GCN 2 x 16: d_in >= 16, so layer 0 updates first
+
+
+def _entry_ops(hlo: str):
+    """(name, result shape, opcode, op_name, line) of the entry's ops."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    out = []
+    for line in entry.splitlines()[1:]:
+        m = _ENTRY_OP.match(line)
+        if m:
+            meta = re.search(r'op_name="([^"]*)"', line)
+            out.append((*m.groups(), meta.group(1) if meta else "", line))
+    return out
+
+
+@pytest.fixture(scope="module")
+def gcn_step_hlo(one_chip):
+    """HLO of a GCN 2 x 16 training step at a tiny size, compiled for one
+    v5e: forward, backward, masked cross-entropy and AdamW."""
+    (dev,) = one_chip.device_set
+    mesh = Mesh(np.array([dev]), ("ring",))
+    g = C.power_law(384, avg_degree=12.0, locality=0.3, seed=1)
+    eng = C.GNNEngine.build(g, mesh, ps=16, dist=2)
+    init, apply, kw = C.MODEL_ZOO["gcn"]
+    params = jax.eval_shape(lambda k: init(k, D_IN, CLASSES, **kw),
+                            jax.random.key(0))
+    opt = jax.eval_shape(O.adamw_init, params)
+    rep = NamedSharding(mesh, PartitionSpec())
+    rows = NamedSharding(mesh, PartitionSpec("ring"))
+    shaped = lambda tree, sh: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+    n = eng.plan.rows_per_dev
+    cfg = O.AdamWConfig()
+
+    def step(params, opt, xp, yp, mp):
+        loss, grads = jax.value_and_grad(lambda p: C.masked_cross_entropy(
+            apply(p, eng, xp), yp, mp))(params)
+        params, opt, _ = O.adamw_update(grads, opt, params, cfg)
+        return params, opt, loss
+
+    return n, jax.jit(step).lower(
+        shaped(params, rep), shaped(opt, rep),
+        jax.ShapeDtypeStruct((n, D_IN), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+    ).compile().as_text()
+
+
+def test_gcn_step_keeps_its_layer_scopes_on_v5e(gcn_step_hlo):
+    """GCN 2 x 16 at a tiny size, compiled for one v5e: the scatter-adds,
+    the row gathers and the (P, ps, D) relayouts, the mask multiply's
+    among them, lie under ``mgg.aggregate`` (the label gather under
+    ``gnn.loss``), and every updated parameter and moment comes out of
+    ``train.optimizer``."""
+    _, hlo = gcn_step_hlo
+    ops_ = _entry_ops(hlo)
+
+    def of(pred):
+        return [(name, scope_of(on)) for name, shape, op, on, _ in ops_
+                if pred(shape, op, on)]
+
+    last = lambda on: on.rsplit("/", 1)[-1]
+    scatters = of(lambda sh, op, on: op == "fusion"
+                  and last(on) == "scatter-add")
+    gathers = of(lambda sh, op, on: op == "fusion" and last(on) == "gather"
+                 and "take_along_axis" not in on)
+    label = of(lambda sh, op, on: "take_along_axis" in on)
+    rank3 = lambda sh: re.match(r"f32\[\d+,\d+,\d+\]", sh) is not None
+    relayouts = of(lambda sh, op, on: op == "copy" and rank3(sh))
+    mask_mul = of(lambda sh, op, on: op == "copy" and rank3(sh)
+                  and last(on) == "mul")
+    assert len(scatters) >= 4 and len(gathers) >= 4, (scatters, gathers)
+    assert len(relayouts) >= 4 and len(mask_mul) >= 2, relayouts
+    for name, scope in scatters + gathers + relayouts:
+        assert scope == S.AGGREGATE, name
+    assert label and all(scope == S.LOSS for _, scope in label)
+
+    root = next(line for *_, line in ops_ if line.lstrip().startswith("ROOT"))
+    outs = re.findall(r"%([\w.\-]+)", root.split(" tuple(", 1)[1])
+    scope_at = {name: scope_of(on) for name, _, _, on, _ in ops_}
+    *state, loss = outs                 # params', opt' ..., then the loss
+    assert all(scope_at[o] == S.OPTIMIZER for o in state), \
+        [(o, scope_at[o]) for o in state]
+    assert scope_at[loss] == S.LOSS
+
+
+def test_gcn_step_matmul_fusions_lie_under_the_update_on_v5e(gcn_step_hlo):
+    """The v5e compiler fuses x.W1 with the aggregation's D^-1/2 scaling and
+    W1's gradient with the optimizer's gradient norm.  The fusions that run
+    those matmuls, and every other op that runs one, are attributed to
+    ``gnn.update`` (``chipbench.scopes.hlo_scopes``), so the dense update's
+    device time is read under its own name."""
+    n, hlo = gcn_step_hlo
+    scopes = hlo_scopes(hlo)
+    matmuls = matmul_ops(hlo)
+    shapes = {sh for found in matmuls.values() for sh in found}
+    assert f"f32[{n},16]" in shapes          # x.W1 (and the hidden grad)
+    assert f"f32[{D_IN},16]" in shapes       # W1's gradient
+    assert f"f32[16,{CLASSES}]" in shapes    # W2's gradient
+    assert all(scopes.get(name) == S.UPDATE for name in matmuls), \
+        [(name, scopes.get(name)) for name in matmuls]
