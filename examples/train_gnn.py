@@ -19,8 +19,9 @@ persists the converged config(s) keyed by workload shape + hardware, so
 the next run warm-starts from it.
 
 ``--metrics-json PATH`` writes the metrics registry: step times, the
-tuner's counters, and JAX's compile events (``jax.compile.*``: compiles and
-their seconds by phase, persistent-cache hits and misses).
+tuner's counters, which backward each aggregation took
+(``mgg.aggregate.vjp``), and JAX's compile events (``jax.compile.*``:
+compiles and their seconds by phase, persistent-cache hits and misses).
 
 ``--sample-fanout F`` switches to the sampled mini-batch path
 (GraphSAGE only): fanout-bounded k-hop blocks (repro.sample) over a
@@ -253,7 +254,8 @@ def train(args, ap, tracer, registry):
         )
     else:
         eng = C.GNNEngine.build(g, mesh, ps=16, dist=2,
-                                fuse_update=args.fuse_update, tracer=tracer)
+                                fuse_update=args.fuse_update, tracer=tracer,
+                                metrics=registry)
     opt = adamw_init(params)
     ocfg = AdamWConfig(lr=5e-3, warmup_steps=5, total_steps=args.steps,
                        weight_decay=0.0)

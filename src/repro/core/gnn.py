@@ -38,13 +38,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.scopes import AGGREGATE, LOSS, UPDATE, scoped
 from repro.obs.tracer import resolve
 
 from .graph import CSRGraph
 from .placement import (AggregationPlan, LayerPlan, SharedPartition,
                         build_layer_plans, build_partition, pad_embeddings,
-                        pad_table)
+                        pad_table, transposed_plan)
 from .pipeline import (block_neighbor_sum, mgg_aggregate,
                        mgg_aggregate_sparse, mgg_aggregate_sparse_streamed,
                        mgg_aggregate_streamed)
@@ -73,6 +74,12 @@ class GNNEngine:
     applies the symmetric normalization; the ``*_update`` variants run the
     layer's dense ``·W`` update fused into the ring (see
     pipeline.mgg_aggregate ``update_w``).
+
+    The dense aggregation's backward runs the ring over the transposed
+    graph (:meth:`backward_plan`); the fused-update and top-k paths keep
+    autodiff's.  ``metrics`` (optional :class:`repro.obs.MetricsRegistry`)
+    counts which backward each traced aggregation takes:
+    ``mgg.aggregate.vjp{path=transposed|symmetric|autodiff}``.
     """
 
     layer_plans: List[LayerPlan]
@@ -81,6 +88,11 @@ class GNNEngine:
     use_kernel: bool = False
     deg: Optional[jax.Array] = None  # padded (N_pad,) float32, degree of A+I
     partition: Optional[SharedPartition] = None
+    graph: Optional[CSRGraph] = None  # the aggregated graph (A+I)
+    metrics: Optional[MetricsRegistry] = None
+    # built at the first traced backward: "partition" -> the transposed
+    # graph's partition (None on a symmetric graph); (ps, dist) -> its plan
+    _backward: Dict = dataclasses.field(default_factory=dict, repr=False)
 
     @staticmethod
     def build(
@@ -99,6 +111,7 @@ class GNNEngine:
         layer_configs: Optional[Sequence[Dict]] = None,
         partition: Optional[SharedPartition] = None,
         tracer=None,
+        metrics: Optional[MetricsRegistry] = None,
     ) -> "GNNEngine":
         """Build an engine; ``layer_configs`` (one ``{ps, dist, pb, ...}``
         dict per layer) selects per-layer plans, otherwise the single
@@ -110,7 +123,8 @@ class GNNEngine:
 
         ``tracer`` (optional :class:`repro.obs.Tracer`) records an
         ``engine.build`` span with the children ``engine.partition`` and
-        ``engine.layer_plans``."""
+        ``engine.layer_plans``; ``metrics`` receives the
+        ``mgg.aggregate.vjp`` counter."""
         tracer = resolve(tracer)
         with tracer.span("engine.build", cat="engine"):
             g = graph.with_self_loops() if self_loops else graph
@@ -134,7 +148,7 @@ class GNNEngine:
                 layer_plans=plans, mesh=mesh, axis_name=axis_name,
                 use_kernel=use_kernel,
                 deg=jnp.asarray(np.maximum(deg, 1.0)),
-                partition=part,
+                partition=part, graph=g, metrics=metrics,
             )
 
     # -- layer plan access ---------------------------------------------------
@@ -195,10 +209,36 @@ class GNNEngine:
 
     # -- aggregation ---------------------------------------------------------
 
+    def _count_vjp(self, path: str) -> None:
+        if self.metrics is not None:
+            self.metrics.counter("mgg.aggregate.vjp", path=path).inc()
+
+    def backward_plan(self, plan: AggregationPlan) -> AggregationPlan:
+        """The plan the backward of an aggregation over ``plan`` runs,
+        ``x̄ = Aᵀ ȳ``: ``plan`` itself when the graph equals its transpose,
+        else placement.transposed_plan.  Built when the first backward is
+        traced and kept (serving and inference never build it); counted
+        once per traced backward."""
+        if "partition" not in self._backward:
+            g_t = self.graph.transpose()
+            self._backward["partition"] = None if _same_edges(self.graph, g_t) \
+                else build_partition(g_t, plan.n_dev, plan.bounds)
+        part_t = self._backward["partition"]
+        if part_t is None:
+            self._count_vjp("symmetric")
+            return plan
+        key = (plan.ps, plan.dist)
+        if key not in self._backward:
+            self._backward[key] = transposed_plan(part_t, plan)
+        self._count_vjp("transposed")
+        return self._backward[key]
+
     def aggregate(self, x: jax.Array, layer: int = 0,
                   update_w: Optional[jax.Array] = None,
                   topk: Optional[int] = None) -> jax.Array:
         lp = self.layer_plan(layer)
+        if topk or update_w is not None:
+            self._count_vjp("autodiff")
         if topk:
             return mgg_aggregate_sparse(
                 x, lp.plan, self.mesh,
@@ -216,6 +256,7 @@ class GNNEngine:
             use_kernel=self.use_kernel,
             pb=lp.pb,
             update_w=update_w,
+            transpose=lambda: self.backward_plan(lp.plan),
         )
 
     def aggregate_update(self, x: jax.Array, w: jax.Array, layer: int = 0,
@@ -294,6 +335,16 @@ class GNNEngine:
         """Fused ``(D^{-1} A x) @ W`` (same commutation as gcn_norm)."""
         return self.aggregate_update(x, w, layer=layer, topk=topk) \
             / self.deg[:, None].astype(x.dtype)
+
+
+def _same_edges(g: CSRGraph, h: CSRGraph) -> bool:
+    """Whether two graphs hold the same edges; ``h`` has sorted rows, as
+    ``CSRGraph.transpose`` gives them."""
+    if not np.array_equal(g.indptr, h.indptr):
+        return False
+    rows = np.repeat(np.arange(g.num_nodes), g.degrees)
+    return np.array_equal(g.indices[np.lexsort((g.indices, rows))],
+                          h.indices)
 
 
 # ---------------------------------------------------------------------------

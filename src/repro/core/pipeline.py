@@ -33,6 +33,14 @@ Three baselines used throughout benchmarks:
   Direct-NVSHMEM baseline (exact rows, no overlap; Table 1); larger values
   model UVM's page-granular migration with its wasted bandwidth (§2.2).
 
+The backward of ``y = A x`` is ``x̄ = Aᵀ ȳ``.  :func:`mgg_aggregate` without
+``update_w`` computes it as the same ring over a plan of the transposed
+graph (``transpose=``; ``GNNEngine`` always passes it): a gather-sum that
+reads ``ȳ``, where autodiff would scatter-add every gathered row.  On a
+symmetric graph that plan is the forward plan itself.  These keep
+autodiff's backward: the fused ``update_w`` path, the top-k sparse paths,
+the streamed paths, :func:`block_neighbor_sum` and the baselines.
+
 Inside a jitted program every aggregation runs under the ``mgg.aggregate``
 scope and each ring rotation under ``mgg.ring`` (``repro.obs.scopes``).
 The streamed path's pieces are jitted on their own, so their bodies carry
@@ -41,7 +49,7 @@ the scope themselves.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +102,21 @@ def _gather_sum(buf: jax.Array, nbrs: jax.Array, mask: jax.Array,
     return jnp.sum(
         g.astype(acc_dtype) * mask[..., None].astype(acc_dtype), axis=1
     )
+
+
+def _padded_gather_sum(buf: jax.Array, nbrs: jax.Array, use_kernel: bool,
+                       acc_dtype, pb: Optional[int] = None) -> jax.Array:
+    """:func:`_gather_sum` over a maskless plan (placement.transposed_plan),
+    whose padding slots index row ``len(buf)``: a zero row appended here,
+    so the sum needs no mask and the compiled program holds none.  The
+    kernel derives its mask from the indices."""
+    if use_kernel:
+        mask = nbrs < buf.shape[0]
+        return _gather_sum(buf, jnp.where(mask, nbrs, 0), mask, True,
+                           acc_dtype, pb)
+    buf = jnp.concatenate([buf, jnp.zeros((1, buf.shape[1]), buf.dtype)])
+    g = jnp.take(buf, nbrs, axis=0, mode="clip")  # (P, ps, D)
+    return jnp.sum(g.astype(acc_dtype), axis=1)
 
 
 @scoped(AGGREGATE)
@@ -178,8 +201,9 @@ def _sparse_gather_sum(values: jax.Array, idx: jax.Array, nbrs: jax.Array,
 
 
 def plan_device_arrays(plan: AggregationPlan) -> Dict[str, np.ndarray]:
-    """The device-resident pytree of an :class:`AggregationPlan`."""
-    return dict(
+    """The device-resident pytree of an :class:`AggregationPlan` (a
+    maskless plan ships no masks)."""
+    arrays = dict(
         local_nbrs=plan.local_nbrs,
         local_mask=plan.local_mask,
         local_targets=plan.local_targets,
@@ -187,6 +211,7 @@ def plan_device_arrays(plan: AggregationPlan) -> Dict[str, np.ndarray]:
         remote_mask=plan.remote_mask,
         remote_targets=plan.remote_targets,
     )
+    return {k: v for k, v in arrays.items() if v is not None}
 
 
 def _plan_specs(axis_name: str) -> Dict[str, P]:
@@ -217,6 +242,7 @@ def mgg_aggregate(
     acc_dtype=jnp.float32,
     pb: Optional[int] = None,
     update_w: Optional[jax.Array] = None,
+    transpose: Optional[Callable[[], AggregationPlan]] = None,
 ) -> jax.Array:
     """Pipelined sum-aggregation: ``out[v] = Σ_{u ∈ N(v)} x[u]``.
 
@@ -224,6 +250,14 @@ def mgg_aggregate(
     sharded by rows over ``axis_name`` (see placement.pad_embeddings); the
     output has the same layout/sharding.  ``pb`` is the paper's wpb knob:
     the partition-block height of the kernel variant (kernel path only).
+
+    ``transpose`` returns the plan of the transposed graph
+    (placement.transposed_plan, or ``plan`` itself on a symmetric graph).
+    Given it, and no ``update_w``, the aggregation's backward is
+    ``x̄ = Aᵀ ȳ``: this same ring over that plan, whose gather-sum reads
+    ``ȳ`` where autodiff would scatter-add every gathered row.  It is
+    called when a backward is traced, so a forward-only program never
+    builds it.
 
     ``update_w`` (``(D, D_out)``, replicated) selects the **fused update**
     path: the output becomes ``(A x) @ W`` and each ring step performs its
@@ -235,6 +269,32 @@ def mgg_aggregate(
     exactly in reals; in floats the two paths differ only by summation
     order (tolerance-tested in tests/test_layer_plans.py).
     """
+    ring = functools.partial(
+        _ring_aggregate, mesh=mesh, axis_name=axis_name,
+        interleave=interleave, use_kernel=use_kernel, acc_dtype=acc_dtype,
+        pb=pb)
+    if update_w is not None or transpose is None:
+        return ring(x, plan, update_w)
+
+    @jax.custom_vjp
+    def aggregate(x):
+        return ring(x, plan)
+
+    def forward(x):
+        return ring(x, plan), None     # linear in x: nothing to keep
+
+    def backward(_, y_bar):
+        return (mgg_aggregate(
+            y_bar, transpose(), mesh, axis_name=axis_name,
+            interleave=interleave, use_kernel=use_kernel,
+            acc_dtype=acc_dtype, pb=pb, transpose=lambda: plan),)
+
+    aggregate.defvjp(forward, backward)
+    return aggregate(x)
+
+
+def _ring_aggregate(x, plan, update_w=None, *, mesh, axis_name, interleave,
+                    use_kernel, acc_dtype, pb):
     n_dev, dist, tile_rows = plan.n_dev, plan.dist, plan.tile_rows
     arrays = jax.tree.map(jnp.asarray, plan_device_arrays(plan))
 
@@ -250,7 +310,7 @@ def mgg_aggregate(
         pb=pb,
         fused=update_w is not None,
     )
-    in_specs = [P(axis_name), _plan_specs(axis_name)]
+    in_specs = [P(axis_name), {k: P(axis_name) for k in arrays}]
     args = [x, arrays]
     if update_w is not None:
         in_specs.append(P(None, None))  # replicated update weight
@@ -271,15 +331,24 @@ def _mgg_shard_body(
     x, arrays, w=None, *, axis_name, n_dev, dist, tile_rows, interleave,
     use_kernel, acc_dtype, pb=None, fused=False,
 ):
-    # Per-device blocks: squeeze the device-major axis.
+    # Per-device blocks: squeeze the device-major axis.  A maskless plan
+    # has no masks: its padding slots index the row past their buffer.
     l_nbrs = arrays["local_nbrs"][0]        # (PL, ps)
-    l_mask = arrays["local_mask"][0]
+    l_mask = arrays["local_mask"][0] if "local_mask" in arrays else None
     l_tgt = arrays["local_targets"][0]      # (PL,)
     r_nbrs = arrays["remote_nbrs"][0]       # (S, PR, ps)
-    r_mask = arrays["remote_mask"][0]
+    r_mask = arrays["remote_mask"][0] if "remote_mask" in arrays else None
     r_tgt = arrays["remote_targets"][0]     # (S, PR)
 
     rows, d_feat = x.shape
+    step_slice = lambda a, i: None if a is None else \
+        lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+    def gather_sum(buf, nbrs, mask):
+        if mask is None:
+            return _padded_gather_sum(buf, nbrs, use_kernel, acc_dtype, pb)
+        return _gather_sum(buf, nbrs, mask, use_kernel, acc_dtype, pb)
+
     if fused:
         wacc = w.astype(acc_dtype)
         d_out = wacc.shape[1]
@@ -301,15 +370,16 @@ def _mgg_shard_body(
         pl_total = l_nbrs.shape[0]
         ls = -(-pl_total // n_steps)  # ceil: local partitions per ring step
         pad = ls * n_steps - pl_total
-        l_nbrs_s = jnp.pad(l_nbrs, ((0, pad), (0, 0))).reshape(n_steps, ls, -1)
-        l_mask_s = jnp.pad(l_mask, ((0, pad), (0, 0))).reshape(n_steps, ls, -1)
+        l_nbrs_s = jnp.pad(l_nbrs, ((0, pad), (0, 0)),
+                           constant_values=0 if l_mask is not None else rows
+                           ).reshape(n_steps, ls, -1)
+        l_mask_s = None if l_mask is None else jnp.pad(
+            l_mask, ((0, pad), (0, 0))).reshape(n_steps, ls, -1)
         l_tgt_s = jnp.pad(l_tgt, ((0, pad),)).reshape(n_steps, ls)
     else:
         # Paper Fig. 9(b) baseline: all local partitions up front, then the
         # (non-overlapped-with-local) remote rounds.
-        out = out.at[l_tgt].add(
-            update(_gather_sum(x, l_nbrs, l_mask, use_kernel, acc_dtype, pb))
-        )
+        out = out.at[l_tgt].add(update(gather_sum(x, l_nbrs, l_mask)))
 
     if n_dev == 1:
         return out.astype(x.dtype)
@@ -319,17 +389,15 @@ def _mgg_shard_body(
 
     def step_work(out, cur, idx):
         """Aggregate remote tile `cur` for ring step `idx` (+ its local slice)."""
-        nbrs = lax.dynamic_index_in_dim(r_nbrs, idx, 0, keepdims=False)
-        mask = lax.dynamic_index_in_dim(r_mask, idx, 0, keepdims=False)
-        tgt = lax.dynamic_index_in_dim(r_tgt, idx, 0, keepdims=False)
-        out = out.at[tgt].add(
-            update(_gather_sum(cur, nbrs, mask, use_kernel, acc_dtype, pb)))
+        nbrs = step_slice(r_nbrs, idx)
+        mask = step_slice(r_mask, idx)
+        tgt = step_slice(r_tgt, idx)
+        out = out.at[tgt].add(update(gather_sum(cur, nbrs, mask)))
         if interleave:
-            ln = lax.dynamic_index_in_dim(l_nbrs_s, idx, 0, keepdims=False)
-            lm = lax.dynamic_index_in_dim(l_mask_s, idx, 0, keepdims=False)
-            lt = lax.dynamic_index_in_dim(l_tgt_s, idx, 0, keepdims=False)
-            out = out.at[lt].add(
-                update(_gather_sum(x, ln, lm, use_kernel, acc_dtype, pb)))
+            ln = step_slice(l_nbrs_s, idx)
+            lm = step_slice(l_mask_s, idx)
+            lt = step_slice(l_tgt_s, idx)
+            out = out.at[lt].add(update(gather_sum(x, ln, lm)))
         return out
 
     # One double-buffered ring per tile chunk (chunk-major, so every chunk

@@ -46,6 +46,7 @@ __all__ = [
     "plan_from_partition",
     "build_plan",
     "build_layer_plans",
+    "transposed_plan",
     "build_bulk_plan",
     "build_fetch_plan",
     "pad_table",
@@ -78,13 +79,19 @@ class AggregationPlan:
     ``rows_per_dev / dist`` is the ring-tile height.  Step ``s`` of the ring
     aggregates the tile of chunk ``s % dist`` from owner
     ``(d - (s // dist) - 1) mod n``.
+
+    A plan without masks (``local_mask`` and ``remote_mask`` None, see
+    :func:`transposed_plan`) marks its padding slots by index instead: each
+    holds the height of the buffer it indexes (``rows_per_dev`` locally,
+    ``tile_rows`` on a ring tile), the row just past it, which the gather
+    reads as zeros.
     """
 
     local_nbrs: np.ndarray
-    local_mask: np.ndarray
+    local_mask: Optional[np.ndarray]
     local_targets: np.ndarray
     remote_nbrs: np.ndarray
-    remote_mask: np.ndarray
+    remote_mask: Optional[np.ndarray]
     remote_targets: np.ndarray
     node_counts: np.ndarray
     bounds: np.ndarray  # (n+1,) global node-range bounds
@@ -351,6 +358,32 @@ def build_layer_plans(
             topk=int(tk) if tk else None,
         ))
     return out
+
+
+def transposed_plan(partition_t: SharedPartition,
+                    plan: AggregationPlan) -> AggregationPlan:
+    """The plan that computes ``x̄ = Aᵀ ȳ``, the backward of an aggregation
+    over ``plan``.  ``partition_t`` is ``build_partition(graph.transpose(),
+    plan.n_dev, plan.bounds)``, shared by the plans of one graph.
+
+    It keeps ``plan``'s node bounds, ``(ps, dist)`` and padded
+    ``rows_per_dev``, so ``x̄`` lands in the PGAS layout of ``x`` and the
+    backward is the same ring, with ``ȳ`` tiles rotating where ``x`` tiles
+    rotated.  It ships no mask: padding slots index the row just past
+    their buffer.
+    """
+    p = plan_from_partition(partition_t, ps=plan.ps, dist=plan.dist,
+                            rows_multiple=plan.rows_per_dev)
+    assert p.rows_per_dev == plan.rows_per_dev
+    return dataclasses.replace(
+        p,
+        local_nbrs=np.where(p.local_mask, p.local_nbrs,
+                            np.int32(p.rows_per_dev)),
+        local_mask=None,
+        remote_nbrs=np.where(p.remote_mask, p.remote_nbrs,
+                             np.int32(p.tile_rows)),
+        remote_mask=None,
+    )
 
 
 def _padded_offset(bounds: np.ndarray, rows: int, ids: np.ndarray) -> np.ndarray:

@@ -297,7 +297,7 @@ class DynamicGNNEngine:
                 layer_configs=[_lc(c) for c in cfg["layers"]],
                 interleave=self.interleave, use_kernel=self.use_kernel,
                 self_loops=self.self_loops, fuse_update=self.fuse_update,
-                partition=self._partition,
+                partition=self._partition, metrics=self.metrics,
             )
         else:
             eng = GNNEngine.build(
@@ -307,7 +307,7 @@ class DynamicGNNEngine:
                 topk=int(cfg["k"]) if cfg.get("k") else None,
                 interleave=self.interleave, use_kernel=self.use_kernel,
                 self_loops=self.self_loops, fuse_update=self.fuse_update,
-                partition=self._partition,
+                partition=self._partition, metrics=self.metrics,
             )
         self._partition = eng.partition
         return eng

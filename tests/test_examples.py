@@ -23,7 +23,8 @@ def test_quickstart_runs_clean():
 
 def test_train_gnn_metrics_json_reports_its_compiles(tmp_path):
     """examples/train_gnn.py --metrics-json carries JAX's compile events
-    (``repro.obs.watch_compiles``) beside the step times."""
+    (``repro.obs.watch_compiles``) and the aggregations' backward paths
+    beside the step times."""
     out = tmp_path / "metrics.json"
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
@@ -34,8 +35,10 @@ def test_train_gnn_metrics_json_reports_its_compiles(tmp_path):
          "--workdir", str(tmp_path / "ckpt")],
         capture_output=True, text=True, env=env, timeout=900)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
-    hist = json.loads(out.read_text())["histograms"]
+    snap = json.loads(out.read_text())
+    hist = snap["histograms"]
     assert hist["train.step_seconds"]["count"] == 2
+    assert snap["counters"]["mgg.aggregate.vjp{path=transposed}"] >= 2
     for phase in ("trace_s", "lower_s", "backend_s"):
         assert hist[f"jax.compile.{phase}"]["count"] >= 1, phase
         assert hist[f"jax.compile.{phase}"]["sum"] > 0, phase

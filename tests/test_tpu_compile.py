@@ -27,7 +27,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 import repro.core as C
-from chipbench.scopes import hlo_scopes, matmul_ops, scope_of
+from chipbench.scopes import computations, hlo_scopes, matmul_ops, scope_of
 from repro.kernels import neighbor_agg, ops
 from repro.obs import scopes as S
 from repro.train import optimizer as O
@@ -122,7 +122,26 @@ def test_unchunked_table_exceeds_smem(one_chip, monkeypatch):
 
 _ENTRY_OP = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (\(.*?\)|\S+) "
                        r"([a-z][a-z0-9\-]*)\(")
+_DIMS = re.compile(r"[a-z][a-z0-9]*\[([\d,]*)\]")
 D_IN, CLASSES = 24, 5   # GCN 2 x 16: d_in >= 16, so layer 0 updates first
+
+
+def _tiny_graph():
+    return C.power_law(384, avg_degree=12.0, locality=0.3, seed=1)
+
+
+def _scatter_update_rows(hlo: str):
+    """Rows (all dimensions but the last) of each scatter's update operand,
+    in every computation of the program."""
+    out = []
+    for instrs in computations(hlo).values():
+        dims = {name: _DIMS.match(rest) for name, _, rest in instrs}
+        for name, op, rest in instrs:
+            if op == "scatter":
+                upd = re.search(r"scatter\(([^)]*)\)", rest).group(1)
+                shape = dims[upd.split(", ")[-1].lstrip("%")].group(1)
+                out.append(int(np.prod([int(d) for d in shape.split(",")][:-1])))
+    return out
 
 
 def _entry_ops(hlo: str):
@@ -144,8 +163,7 @@ def gcn_step_hlo(one_chip):
     v5e: forward, backward, masked cross-entropy and AdamW."""
     (dev,) = one_chip.device_set
     mesh = Mesh(np.array([dev]), ("ring",))
-    g = C.power_law(384, avg_degree=12.0, locality=0.3, seed=1)
-    eng = C.GNNEngine.build(g, mesh, ps=16, dist=2)
+    eng = C.GNNEngine.build(_tiny_graph(), mesh, ps=16, dist=2)
     init, apply, kw = C.MODEL_ZOO["gcn"]
     params = jax.eval_shape(lambda k: init(k, D_IN, CLASSES, **kw),
                             jax.random.key(0))
@@ -173,10 +191,9 @@ def gcn_step_hlo(one_chip):
 
 def test_gcn_step_keeps_its_layer_scopes_on_v5e(gcn_step_hlo):
     """GCN 2 x 16 at a tiny size, compiled for one v5e: the scatter-adds,
-    the row gathers and the (P, ps, D) relayouts, the mask multiply's
-    among them, lie under ``mgg.aggregate`` (the label gather under
-    ``gnn.loss``), and every updated parameter and moment comes out of
-    ``train.optimizer``."""
+    the row gathers and any (P, ps, D) relayout lie under
+    ``mgg.aggregate`` (the label gather under ``gnn.loss``), and every
+    updated parameter and moment comes out of ``train.optimizer``."""
     _, hlo = gcn_step_hlo
     ops_ = _entry_ops(hlo)
 
@@ -192,10 +209,7 @@ def test_gcn_step_keeps_its_layer_scopes_on_v5e(gcn_step_hlo):
     label = of(lambda sh, op, on: "take_along_axis" in on)
     rank3 = lambda sh: re.match(r"f32\[\d+,\d+,\d+\]", sh) is not None
     relayouts = of(lambda sh, op, on: op == "copy" and rank3(sh))
-    mask_mul = of(lambda sh, op, on: op == "copy" and rank3(sh)
-                  and last(on) == "mul")
     assert len(scatters) >= 4 and len(gathers) >= 4, (scatters, gathers)
-    assert len(relayouts) >= 4 and len(mask_mul) >= 2, relayouts
     for name, scope in scatters + gathers + relayouts:
         assert scope == S.AGGREGATE, name
     assert label and all(scope == S.LOSS for _, scope in label)
@@ -224,3 +238,24 @@ def test_gcn_step_matmul_fusions_lie_under_the_update_on_v5e(gcn_step_hlo):
     assert f"f32[16,{CLASSES}]" in shapes    # W2's gradient
     assert all(scopes.get(name) == S.UPDATE for name in matmuls), \
         [(name, scopes.get(name)) for name in matmuls]
+
+
+def test_gcn_step_backward_gathers_over_the_transposed_plan_on_v5e(
+        gcn_step_hlo):
+    """The aggregations' backward is the ring over the transposed plan: the
+    step holds no scatter of the P·ps gathered rows (autodiff's transpose
+    of the row gather), only the scatters of partition partials, and the
+    backward's row gathers lie under ``mgg.aggregate``."""
+    _, hlo = gcn_step_hlo
+    g = _tiny_graph().with_self_loops()
+    p_ps = C.build_plan(g, 1, ps=16, dist=2).local_nbrs.shape[1] * 16
+    rows = _scatter_update_rows(hlo)
+    assert len(rows) >= 4 and p_ps not in rows, (p_ps, rows)
+
+    scopes = hlo_scopes(hlo)
+    backward = [name for name, _, op, on, _ in _entry_ops(hlo)
+                if op == "fusion" and on.rsplit("/", 1)[-1] == "gather"
+                and "transpose(jvp(" in on]
+    assert len(backward) >= 2, backward
+    assert all(scopes.get(name) == S.AGGREGATE for name in backward), \
+        [(name, scopes.get(name)) for name in backward]
